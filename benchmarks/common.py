@@ -1,8 +1,10 @@
 """Benchmark harness utilities: timing, graph/table setup, CSV output.
 
-Absolute times on this 1-core container are not comparable to the paper's
-32-core server; the paper's CLAIMS are about *ratios between
-representations*, which are preserved (DESIGN.md §8).
+The suites run the paper's graph families at scale 12 on whatever
+platform JAX finds, and their rows do not name a device.  The checked-in
+``BENCH_*.json`` rows were timed on a one-core CPU container: they
+measure XLA's CPU backend at cache-resident sizes, not the TPU, and are
+kept as a trajectory of that setting, not as the system's result.
 """
 from __future__ import annotations
 

@@ -141,6 +141,9 @@ def main() -> None:
         f"fail on >{REGRESSION_FACTOR}x regression of any digraph row",
     )
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         bench_alloc,
         bench_clone,

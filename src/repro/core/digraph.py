@@ -149,13 +149,12 @@ class DiGraph:
     # construction
     # ------------------------------------------------------------------
     @classmethod
-    def from_csr(cls, c: csr_mod.CSR, *, engine: str = "auto") -> "DiGraph":
+    def from_csr(cls, c: csr_mod.CSR) -> "DiGraph":
         """Direct CSR -> arena-image construction (DESIGN.md §10).
 
         Host metadata (CP2AA block placement) stays numpy; the device
         payload comes from ``kernels/csr_build.arena_image`` — a numpy
-        shifted-offset fill + one transfer off-TPU, or a fused on-device
-        scatter program on TPU (no host round-trip for a device CSR).
+        shifted-offset fill + one transfer.
         """
         offsets_h = np.asarray(c.offsets, dtype=np.int64)
         degrees = np.diff(offsets_h)
@@ -174,9 +173,7 @@ class DiGraph:
 
         wgt_src = c.wgt if c.wgt is not None else np.ones(c.m, np.float32)
         dst_d, wgt_d, rows_d = _cb_ops.arena_image(
-            c.offsets, c.dst, wgt_src,
-            starts[: c.n], caps[: c.n], cap_e, n_cap,
-            total=total, engine=engine,
+            c.offsets, c.dst, wgt_src, starts[: c.n], caps[: c.n], cap_e, n_cap,
         )
         exists = np.zeros(n_cap, bool)
         exists[: c.n] = True
@@ -389,13 +386,8 @@ class DiGraph:
         # ~cap_e-proportional constant (~5ns/slot/array + the host slot
         # map) while scatters pay ~100ns per touched slot, so only a big
         # arena with a proportionally tiny batch takes the scatter path
-        # (keeping small updates O(batch), not O(|E|)).  The Pallas
-        # merge is only exact for ids < 2**24 (f32 one-hot matmuls), so
-        # huge-vertex graphs fall back to the XLA merge.
-        on_tpu = jax.default_backend() == "tpu"
-        merge_backend = (
-            "pallas" if on_tpu and self.cap_v < _su_ops.PALLAS_MAX_ID else "xla"
-        )
+        # (keeping small updates O(batch), not O(|E|)).
+        merge_backend = "pallas" if jax.default_backend() == "tpu" else "xla"
         touched = int(new_caps.sum() + old_caps[grow].sum())
         use_scatter = _su_ops.choose_scatter(self.cap_e, touched)
         has_moves = bool(grow.any())

@@ -277,14 +277,13 @@ class WalkImage:
     # ------------------------------------------------------------------
     @classmethod
     def from_csr_arrays(cls, offsets, dst, wgt, nv: int, *,
-                        engine: str = "auto",
                         dense: Optional[bool] = None,
                         min_cap_e: int = 0) -> "WalkImage":
         """Build a slack-padded OR dense image from CSR-ordered arrays.
 
         Reuses the ingest engine's ``arena_image`` fill (DESIGN.md §10):
-        CP2AA block placement on host, one fused fill + transfer for the
-        device payload.  ``dense=None`` applies the §12 compaction
+        CP2AA block placement and a numpy fill on host, one transfer for
+        the device payload.  ``dense=None`` applies the §12 compaction
         policy: when the CP2AA layout's live fraction would fall below
         ``DENSE_THRESHOLD``, blocks take their exact degree (occupancy
         1.0) so the walk processes live edges only.  ``cap_e`` keeps
@@ -311,13 +310,10 @@ class WalkImage:
         cap_e = alloc.pow2_with_headroom(total, 1.0 if dense else 0.25)
         cap_e = max(cap_e, int(min_cap_e))
         w = wgt if wgt is not None else np.ones(m, np.float32)
-        # slice padded source buffers to the live prefix: the device
-        # arena_image path derives its edge count (and jit-cache key)
-        # from dst.shape[0], so SENTINEL tail capacity would be scattered
-        # for nothing on TPU
+        # slice padded source buffers to the live prefix: the SENTINEL
+        # tail capacity would be copied to the host for nothing
         dst_d, wgt_d, rows_d = _cb_ops.arena_image(
             o, dst[:m], w[:m], starts, caps, cap_e, nv,
-            total=total, engine=engine,
         )
         STATS["builds"] += 1
         return cls(
@@ -480,10 +476,7 @@ class WalkImage:
             new_starts[g_idx] = self.bump + (np.cumsum(need) - need)
             self.bump += int(need.sum())
 
-        on_tpu = jax.default_backend() == "tpu"
-        backend = (
-            "pallas" if on_tpu and self.nv < _su_ops.PALLAS_MAX_ID else "xla"
-        )
+        backend = "pallas" if jax.default_backend() == "tpu" else "xla"
         has_moves = bool(grow.any())
         touched = int(new_caps.sum() + old_caps[grow].sum())
         scatter = _su_ops.choose_scatter(self.cap_e, touched)

@@ -1,18 +1,19 @@
 """Pallas TPU kernel: partitioned per-vertex degree counting (paper Alg 5).
 
 The paper's loader counts degrees in parallel partitions and merges the
-partial histograms; on TPU the partition becomes an *edge tile* and the
-merge becomes grid accumulation.  Grid = (vertex tiles × edge tiles):
-each step compares one 128-wide src tile against one 128-wide vertex-id
-tile and folds the match count into the output block, so the histogram is
-built from O(M·N/128²) VPU compares with no scatters (TPU scatters
-serialize; dense compare+reduce tiles don't).
+partial histograms; on TPU the partition becomes an *edge block* and the
+merge becomes grid accumulation.  Grid = (vertex blocks × edge blocks):
+each step folds a block of 128-wide src tiles into a block of 128-wide
+vertex-id rows, one tile at a time, as a one-hot matmul —
+``[row, edge] @ [edge, lane]`` counts every edge at (its id // 128, its
+id % 128) — so the histogram is built with no scatters (TPU scatters
+serialize; dense compare + matmul tiles don't).  Counts are sums of 0/1
+products, exact in f32 for any block size used here.
 
-Ids are compared as int32 — exact for any int32 vertex id, so unlike the
-slot_update merge kernel this path has no 2**24 id ceiling.
+Ids are compared as int32, so every int32 vertex id counts exactly.
 
 Inputs (ops.py pads to whole tiles):
-  src [T, EB] int32 edge sources; pad slots carry ``n_pad`` (out of range)
+  src [T, EB] int32 edge sources; pad slots carry an id outside [0, nv)
 Output:
   degrees [NV] int32, NV a multiple of the 128-lane vertex tile
 """
@@ -24,8 +25,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import row_tiling
+
 #: edge-tile / vertex-tile width (one VPU lane row)
 EB = 128
+#: vertex rows / edge tiles per grid step
+VERTEX_ROWS = 64
+EDGE_ROWS = 64
 
 
 def _kernel(src_ref, deg_ref):
@@ -35,12 +41,25 @@ def _kernel(src_ref, deg_ref):
     def _():
         deg_ref[...] = jnp.zeros_like(deg_ref)
 
-    i = pl.program_id(0)
-    src = src_ref[0]                          # [EB] edge tile
-    # this block's vertex ids: i*EB + lane
-    vg = i * EB + jax.lax.broadcasted_iota(jnp.int32, (1, EB), 1)
-    hits = (src[:, None] == vg).astype(jnp.int32)   # [EB, EB]
-    deg_ref[...] += jnp.sum(hits, axis=0, keepdims=True)
+    vr, eb = deg_ref.shape
+    base = pl.program_id(0) * (vr * eb)   # first vertex id of this block
+    row_id = jax.lax.broadcasted_iota(jnp.int32, (vr, eb), 0)
+    lane_id = jax.lax.broadcasted_iota(jnp.int32, (eb, eb), 1)
+
+    def one_tile(r, acc):
+        off = src_ref[pl.ds(r, 1), :] - base           # [1, EB] edge tile
+        ok = (off >= 0) & (off < vr * eb)
+        rows = (row_id == off // eb) & ok               # [vertex row, edge]
+        lanes = lane_id == (off % eb).reshape(eb, 1)    # [edge, lane]
+        return acc + jnp.dot(
+            rows.astype(jnp.float32), lanes.astype(jnp.float32),
+            preferred_element_type=jnp.float32,
+        )
+
+    acc = jax.lax.fori_loop(
+        0, src_ref.shape[0], one_tile, jnp.zeros((vr, eb), jnp.float32)
+    )
+    deg_ref[...] += acc.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("nv", "interpret"))
@@ -49,18 +68,24 @@ def count_degrees_pallas(src_tiles: jnp.ndarray, *, nv: int,
     """Degree histogram of src_tiles [T, EB] over ``nv`` vertices.
 
     ``nv`` must be a multiple of EB (ops.py rounds); pad edges must carry
-    an id >= nv so they fall outside every vertex tile.
+    an id outside [0, nv) so they fall outside every vertex tile.
     """
     t, eb = src_tiles.shape
     assert eb == EB, f"edge tiles must be {EB} wide, got {eb}"
     nv = int(nv)
     assert nv % EB == 0, f"vertex range must be a multiple of {EB}"
+    t_pad, er = row_tiling(t, EDGE_ROWS)
+    if t_pad != t:
+        src_tiles = jnp.pad(
+            src_tiles, ((0, t_pad - t), (0, 0)), constant_values=-1
+        )
+    v_pad, vr = row_tiling(nv // EB, VERTEX_ROWS)
     deg = pl.pallas_call(
         _kernel,
-        grid=(nv // EB, t),
-        in_specs=[pl.BlockSpec((1, EB), lambda i, j: (j, 0))],
-        out_specs=pl.BlockSpec((1, EB), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nv // EB, EB), jnp.int32),
+        grid=(v_pad // vr, t_pad // er),
+        in_specs=[pl.BlockSpec((er, EB), lambda i, j: (j, 0))],
+        out_specs=pl.BlockSpec((vr, EB), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((v_pad, EB), jnp.int32),
         interpret=interpret,
     )(src_tiles)
-    return deg.reshape(nv)
+    return deg.reshape(-1)[:nv]

@@ -21,9 +21,8 @@ fill) and realizes it as a counting-sort build with three engines:
           for parity tests elsewhere).
 
 ``arena_image`` builds the DiGraph slotted-arena payload (dst/wgt/
-slot_rows) straight from CSR arrays — host formulation off-TPU, fused
-XLA scatter program on TPU — so load never materializes an intermediate
-python-object graph.  ``pages_image`` is the same fill quantized to
+slot_rows) straight from CSR arrays with a numpy fill and one transfer,
+so load never materializes an intermediate python-object graph.  ``pages_image`` is the same fill quantized to
 ChunkedGraph's PAGE-sized chunks.
 """
 from __future__ import annotations
@@ -202,67 +201,20 @@ def arena_image_host(offsets, dst, wgt, starts, caps, cap_e: int, cap_v: int):
     return a_dst, a_wgt, a_rows
 
 
-@functools.lru_cache(maxsize=None)
-def _jit_arena_image(cap_e: int, cap_v: int, n: int, m: int):
-    """Fused device arena fill: expand rows, scatter edges, paint owners."""
+def arena_image(offsets, dst, wgt, starts, caps, cap_e: int, cap_v: int):
+    """The arena payload as three jnp arrays: the numpy fill + one transfer.
 
-    def fn(offsets, dst, wgt, starts, caps, total):
-        row = util.expand_rows(offsets, m)              # row id per edge
-        ok = row < n
-        slot = jnp.where(
-            ok, starts[jnp.clip(row, 0, n - 1)] + (
-                jnp.arange(m, dtype=jnp.int32) - offsets[jnp.clip(row, 0, n - 1)]
-            ), cap_e,
-        )
-        a_dst = jnp.full((cap_e,), SENTINEL, jnp.int32).at[slot].set(
-            dst[:m], mode="drop", unique_indices=True
-        )
-        a_wgt = jnp.zeros((cap_e,), jnp.float32).at[slot].set(
-            wgt[:m], mode="drop", unique_indices=True
-        )
-        # owner per block slot: searchsorted into the block-start cumsum
-        bend = jnp.cumsum(caps, dtype=jnp.int32)        # block end per row
-        pos = jnp.arange(cap_e, dtype=jnp.int32)
-        owner = jnp.searchsorted(bend, pos, side="right").astype(jnp.int32)
-        a_rows = jnp.where(pos < total, jnp.minimum(owner, cap_v), cap_v)
-        return a_dst, a_wgt, a_rows
-
-    return jax.jit(fn)
-
-
-def arena_image_device(offsets, dst, wgt, starts, caps, cap_e: int, cap_v: int,
-                       *, total: int):
-    n = int(np.asarray(offsets).shape[0]) - 1
-    m = int(np.asarray(dst).shape[0])
-    return _jit_arena_image(int(cap_e), int(cap_v), n, m)(
-        jnp.asarray(offsets, jnp.int32),
-        jnp.asarray(dst, jnp.int32),
-        jnp.asarray(wgt, jnp.float32),
-        jnp.asarray(starts, jnp.int32),
-        jnp.asarray(caps, jnp.int32),
-        jnp.int32(total),
-    )
-
-
-def arena_image(offsets, dst, wgt, starts, caps, cap_e: int, cap_v: int,
-                *, total: int, engine: str = "auto"):
-    """Backend-dispatched arena build; returns three jnp arrays.
-
-    Off-TPU the numpy fill + one transfer beats XLA CPU scatters (~100ns
-    per scattered slot); on TPU the fused program keeps everything
-    device-resident.
+    Off-TPU it beats XLA CPU scatters (~100ns per scattered slot).  On a
+    TPU v5e a fused device fill, whose two ``searchsorted`` over every
+    edge and every arena slot are gather loops, took 231 s for the
+    scale-22 Graph500 build (1.3e8 edges, 2^28 slots); the host fill
+    took seconds.
     """
-    if engine == "auto":
-        engine = default_engine()
-    if engine == "host":
-        a_dst, a_wgt, a_rows = arena_image_host(
-            np.asarray(offsets), np.asarray(dst), np.asarray(wgt),
-            np.asarray(starts), np.asarray(caps), cap_e, cap_v,
-        )
-        return jnp.asarray(a_dst), jnp.asarray(a_wgt), jnp.asarray(a_rows)
-    return arena_image_device(
-        offsets, dst, wgt, starts, caps, cap_e, cap_v, total=total
+    a_dst, a_wgt, a_rows = arena_image_host(
+        np.asarray(offsets), np.asarray(dst), np.asarray(wgt),
+        np.asarray(starts), np.asarray(caps), cap_e, cap_v,
     )
+    return jnp.asarray(a_dst), jnp.asarray(a_wgt), jnp.asarray(a_rows)
 
 
 # ---------------------------------------------------------------------------

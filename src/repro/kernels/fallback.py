@@ -28,6 +28,12 @@ The last link of a chain is always attempted even when its breaker is open
 (there is nothing further to fall back to); if it too fails,
 :class:`FallbackExhausted` carries the final error.
 
+A fall-through is never silent: every dispatch that leaves a link for the
+next one counts in ``breaker.fallthroughs[site]``, and the first trip of
+each (site, backend) logs its cause with the traceback.  A program that
+must prove it served from a given backend (the chip smoke run) reads the
+counter and ``LAST_USED``.
+
 ``faultinject.fire(f"{site}.{backend}")`` runs *before* every attempt, so
 injected kernel failures hit with operands untouched — which also means a
 donated-buffer first attempt can always be retried on the next link.  A
@@ -41,6 +47,8 @@ a process kill is not a kernel failure.
 """
 from __future__ import annotations
 
+import collections
+import logging
 import threading
 import time
 from typing import Callable, Optional
@@ -58,6 +66,8 @@ RETRIES = 1
 
 #: site -> backend that served the most recent successful dispatch
 LAST_USED: dict = {}
+
+log = logging.getLogger(__name__)
 
 
 class FallbackExhausted(RuntimeError):
@@ -87,6 +97,9 @@ class CircuitBreaker:
         # key -> {"trips": int, "open_until": float, "probe_until": float}
         # probe_until > 0 means a half-open probe is in flight until then
         self._state: dict = {}
+        #: site -> dispatches that fell through to a later link
+        self.fallthroughs: collections.Counter = collections.Counter()
+        self._logged: set = set()  # keys whose first trip was logged
 
     def available(self, key) -> bool:
         with self._lock:
@@ -111,6 +124,18 @@ class CircuitBreaker:
             # after one base cool-down instead of stranding the backend
             st["probe_until"] = now + self.cooldown
             return "probe"
+
+    def record_fallthrough(self, site: str) -> None:
+        with self._lock:
+            self.fallthroughs[site] += 1
+
+    def first_trip(self, key) -> bool:
+        """True exactly once per key: the first trip, whose cause is logged."""
+        with self._lock:
+            if key in self._logged:
+                return False
+            self._logged.add(key)
+            return True
 
     def trip(self, key) -> None:
         with self._lock:
@@ -137,6 +162,8 @@ class CircuitBreaker:
     def reset(self) -> None:
         with self._lock:
             self._state.clear()
+            self.fallthroughs.clear()
+            self._logged.clear()
 
 
 #: process-wide breaker shared by all chained dispatch sites
@@ -155,9 +182,11 @@ def run_chain(site: str, backend: str, attempt: Callable, *, breaker: Optional[C
     last_err: Optional[Exception] = None
     for i, b in enumerate(candidates):
         key = (site, b)
+        last = i == len(candidates) - 1
         mode = br.admit(key)
         if mode is None:
-            if i < len(candidates) - 1:
+            if not last:
+                br.record_fallthrough(site)
                 continue  # cooling down; the chain floor always gets a shot
             mode = "probe"  # open floor: one attempt, nothing to fall to
         # half-open probes get exactly one attempt; closed links retry-once
@@ -173,6 +202,14 @@ def run_chain(site: str, backend: str, attempt: Callable, *, breaker: Optional[C
             LAST_USED[site] = b
             return out, b
         br.trip(key)
+        if br.first_trip(key):
+            log.warning(
+                "%s: backend %r tripped after %d failed attempt(s)%s",
+                site, b, tries, "" if last else "; falling through",
+                exc_info=last_err,
+            )
+        if not last:
+            br.record_fallthrough(site)
     raise FallbackExhausted(
         f"{site}: all backends failed (chain {candidates}, requested {backend!r})"
     ) from last_err

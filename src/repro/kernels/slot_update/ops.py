@@ -22,9 +22,10 @@ program (DESIGN.md §9/§12) — the per-group ``slot_update`` /
            steady-state stream round.
 
 Buffer donation keeps the arena update in place; every operand shape is
-pow-2 bucketed so steady-state streams never recompile.  The Pallas
-backend places int32 ids via f32 matmuls and therefore requires vertex
-ids < 2**24; ``auto`` only selects it on TPU.
+pow-2 bucketed so steady-state streams never recompile.  ``auto`` selects
+the Pallas backend on TPU; its groups wider than the kernel's
+``MAX_WIDTH`` (hub rows) merge with the XLA formulation inside the same
+program, counted in ``STATS["wide_groups"]``.
 """
 from __future__ import annotations
 
@@ -51,17 +52,12 @@ REBUILD_MAX_CAP = 1 << 21
 #: so padding every small class to 128 lanes would inflate it ~10x.
 EB = 128
 XLA_FLOOR = 8
-#: The Pallas kernel places int32 vertex ids through f32 matmuls, which
-#: are exact only below the f32 mantissa bound.  Callers must route
-#: graphs with ids >= this to the XLA formulation (DiGraph does, by
-#: cap_v) — above it the kernel silently rounds ids to the nearest
-#: representable float.
-PALLAS_MAX_ID = 1 << 24
-
-#: Module-level dispatch counter: each ``fused_apply`` call is one device
+#: Module-level dispatch counters: each ``fused_apply`` call is one device
 #: program.  The sharded layer reads deltas to prove every shard's flush
 #: stays at round_dispatches=1 per device (DESIGN.md §14).
-STATS = {"dispatches": 0}
+#: ``wide_groups`` counts width groups a Pallas-backend program merged
+#: with the XLA formulation because they exceed the kernel's MAX_WIDTH.
+STATS = {"dispatches": 0, "wide_groups": 0}
 
 
 def stats_snapshot() -> dict:
@@ -245,6 +241,10 @@ def _merge_rows_xla(d_rows, w_rows, degs, b_dst, b_wgt, b_del,
     return d_out, w_out, counts
 
 
+def _pallas_fits(width: int, k: int) -> bool:
+    return max(int(width), int(k)) <= _kernel.MAX_WIDTH
+
+
 def merge_rows(
     d_rows, w_rows, degs, b_dst, b_wgt, b_del, *, backend="xla",
     interpret=False, max_holes=None,
@@ -252,13 +252,15 @@ def merge_rows(
     """Backend-dispatched row merge (parity-test entry point).
 
     ``max_holes`` (static) bounds the delete-hole compaction window of
-    the XLA formulation; None means the full run width.
+    the XLA formulation; None means the full run width.  The Pallas
+    backend merges rows up to the kernel's ``MAX_WIDTH`` (and runs up to
+    as many ops); wider ones take the XLA formulation.
     """
-    if backend == "pallas":
+    if backend == "pallas" and _pallas_fits(d_rows.shape[1], b_dst.shape[1]):
         return _kernel.merge_rows_pallas(
             d_rows, w_rows, degs, b_dst, b_wgt, b_del, interpret=interpret
         )
-    if backend == "xla":
+    if backend in ("pallas", "xla"):
         return _merge_rows_xla(
             d_rows, w_rows, degs, b_dst, b_wgt, b_del, max_holes=max_holes
         )
@@ -640,8 +642,12 @@ def fused_apply(
             *ops_flat,
         )
 
-    out, _used = _fb.run_chain("slot_update", backend, _dispatch)
+    out, used = _fb.run_chain("slot_update", backend, _dispatch)
     STATS["dispatches"] += 1
+    if used == "pallas":
+        STATS["wide_groups"] += sum(
+            not _pallas_fits(w, k) for w, _a, k, _dk, _mv in gkey
+        )
     i = 2
     if any_moves:
         new_rows = out[i]

@@ -37,6 +37,13 @@ SENTINEL = util.SENTINEL
 EB = 128  # slots per tile (MXU-native)
 
 
+def _tiles(e: int) -> int:
+    """Tiles covering ``e`` slots, rounded up to a multiple of 8 so the
+    tile planes (and their [B*T, EB] stacks) split into whole Pallas
+    blocks without a padding copy inside the step loop."""
+    return -(-max(-(-e // EB), 1) // 8) * 8
+
+
 def _prep(dst, slot_rows, num_vertices: int, edges_hi: int):
     """Slice the live prefix, mask dead slots, pad to whole tiles.
 
@@ -46,7 +53,7 @@ def _prep(dst, slot_rows, num_vertices: int, edges_hi: int):
     scan).
     """
     e = min(int(edges_hi), dst.shape[0])
-    t = max(-(-e // EB), 1)
+    t = _tiles(e)
     e_pad = t * EB
     sink = num_vertices
     d = dst[:e]
@@ -142,11 +149,32 @@ def _comp_combine(l, r):
     return s, l[1] + r[1] + e
 
 
-def _comp_scan(x, axis=0):
-    """Compensated inclusive scan: returns (hi, lo) with hi+lo ≈ exact."""
-    return jax.lax.associative_scan(
-        _comp_combine, (x, jnp.zeros_like(x)), axis=axis
+def _comp_scan(h, l):
+    """Inclusive compensated scan of (hi, lo) pairs [B, n] along axis 1:
+    returns (hi, lo) with hi+lo ≈ exact (pass ``l = 0`` for plain values).
+
+    Blocked by EB: each EB-long block scans on its own, the block totals
+    scan recursively, and every lane adds its block's exclusive base.
+    One ``associative_scan`` over a long axis makes the TPU compiler's
+    time grow with the axis (minutes at 10^6 tiles); EB-long rows keep
+    it to seconds.
+    """
+    b, n = h.shape
+    if n <= EB:
+        return jax.lax.associative_scan(_comp_combine, (h, l), axis=1)
+    m = -(-n // EB)
+    pad = ((0, 0), (0, m * EB - n))
+    ih, il = jax.lax.associative_scan(
+        _comp_combine,
+        (jnp.pad(h, pad).reshape(b, m, EB), jnp.pad(l, pad).reshape(b, m, EB)),
+        axis=2,
     )
+    bh, bl = _comp_scan(ih[:, :, -1], il[:, :, -1])
+    zero = jnp.zeros((b, 1), h.dtype)
+    eh = jnp.concatenate([zero, bh[:, :-1]], axis=1)[:, :, None]
+    el = jnp.concatenate([zero, bl[:, :-1]], axis=1)[:, :, None]
+    oh, ol = _comp_combine((eh, el), (ih, il))
+    return oh.reshape(b, m * EB)[:, :n], ol.reshape(b, m * EB)[:, :n]
 
 
 def _prep_gidx(dst, num_vertices: int, edges_hi: int):
@@ -158,7 +186,7 @@ def _prep_gidx(dst, num_vertices: int, edges_hi: int):
     per-slot operand is this one int32 index plane (DESIGN.md §12).
     """
     e = min(int(edges_hi), dst.shape[0])
-    t = max(-(-e // EB), 1)
+    t = _tiles(e)
     e_pad = t * EB
     d = dst[:e]
     gidx = jnp.where(
@@ -214,8 +242,8 @@ def make_blocked_step(gidx_p, block_lo, block_hi, num_vertices: int, *,
     # is ever materialized in the loop
     z_lo = r_lo == 0
     z_hi = r_hi == 0
-    i_lo = q_lo * EB + jnp.maximum(r_lo - 1, 0)
-    i_hi = q_hi * EB + jnp.maximum(r_hi - 1, 0)
+    l_lo = jnp.maximum(r_lo - 1, 0)
+    l_hi = jnp.maximum(r_hi - 1, 0)
 
     def step(visits):  # [B, num_vertices] -> [B, num_vertices]
         b = visits.shape[0]
@@ -224,15 +252,21 @@ def make_blocked_step(gidx_p, block_lo, block_hi, num_vertices: int, *,
         if engine == "pallas":
             incl = _kernel.tile_cumsum(
                 vals.reshape(b * t, EB), interpret=interpret
-            ).reshape(b, t, EB)
+            )
         else:
-            incl = jnp.cumsum(vals, axis=2)
-        bh, bl = _comp_scan(incl[:, :, -1], axis=1)  # inclusive tile bases
-        bh = jnp.concatenate([zrow, bh[:, :-1]], axis=1)  # -> exclusive
+            incl = jnp.cumsum(vals, axis=2).reshape(b * t, EB)
+        # inclusive tile bases, then exclusive
+        tot = incl[:, -1].reshape(b, t)
+        bh, bl = _comp_scan(tot, jnp.zeros_like(tot))
+        bh = jnp.concatenate([zrow, bh[:, :-1]], axis=1)
         bl = jnp.concatenate([zrow, bl[:, :-1]], axis=1)
-        incl_f = incl.reshape(b, -1)
-        ih = jnp.where(z_hi, 0.0, jnp.take(incl_f, i_hi, axis=1))
-        il = jnp.where(z_lo, 0.0, jnp.take(incl_f, i_lo, axis=1))
+        # (tile row, lane) reads from the [B*T, EB] prefix as the kernel
+        # lays it out: a flat [B, T*EB] view, or a [B, T, EB] one, costs
+        # the TPU compiler a relayout copy (and ~30 s of compile at 10^7
+        # slots)
+        row0 = jnp.arange(b, dtype=jnp.int32)[:, None] * t
+        ih = jnp.where(z_hi, 0.0, incl[row0 + q_hi, l_hi])
+        il = jnp.where(z_lo, 0.0, incl[row0 + q_lo, l_lo])
         return (jnp.take(bh, q_hi, axis=1) - jnp.take(bh, q_lo, axis=1)) + (
             (ih - il)
             + (jnp.take(bl, q_hi, axis=1) - jnp.take(bl, q_lo, axis=1))

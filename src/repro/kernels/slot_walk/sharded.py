@@ -30,9 +30,9 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import ClosedJaxpr as _ClosedJaxpr, Jaxpr as _Jaxpr
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ...launch import mesh as mesh_mod
 from . import ops as _ops
 
 
@@ -57,7 +57,7 @@ def make_sharded_walk(
     One device program for the whole k-step walk; per step each shard
     computes its own visits slice and ``all_gather``s the frontier
     (tiled, so the output IS the next [B, v_pad] frontier).  The result
-    is replicated — ``check=False`` because jax cannot prove an
+    is replicated — ``check_vma=False`` because jax cannot prove an
     all_gather'ed value replicated across the unrolled scan.
     """
     v_pad = n_shards * rows_max
@@ -75,12 +75,12 @@ def make_sharded_walk(
         vis, _ = jax.lax.scan(one, vis, None, length=steps)
         return vis
 
-    fn = mesh_mod.shard_map_compat(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P("data", None), P("data", None), P("data", None), P()),
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -126,11 +126,6 @@ def make_local_walk(
 # ---------------------------------------------------------------------------
 _RECV_COLLECTIVES = ("all_gather", "all_gather_invariant")
 _MOVE_COLLECTIVES = ("ppermute", "all_to_all", "pgather")
-
-try:  # jaxpr container types moved under jax.extend on newer jax
-    from jax.extend.core import ClosedJaxpr as _ClosedJaxpr, Jaxpr as _Jaxpr
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.core import ClosedJaxpr as _ClosedJaxpr, Jaxpr as _Jaxpr
 
 
 def _aval_bytes(v) -> int:

@@ -1,66 +1,12 @@
-"""Production mesh builders and the shard_map entry point (DESIGN.md §5, §14).
+"""Production mesh builders (DESIGN.md §5, §14).
 
 Functions, not module constants — importing this module never touches jax
 device state.  The dry-run (and only the dry-run) forces 512 host devices.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types; older versions imply Auto
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-
-
-def _resolve_shard_map():
-    """Locate shard_map across jax versions (top-level vs experimental)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map as fn  # jax <= 0.4.x
-    return fn
-
-
-def _check_kwarg(fn) -> str | None:
-    """Name of the replication-check kwarg this jax spells, if inspectable.
-
-    jax <= 0.4.x calls it ``check_rep``; >= 0.5 renamed it ``check_vma``.
-    Returns ``None`` when the signature is opaque (C++ wrappers) — the
-    caller then falls back to trying both spellings.
-    """
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):  # pragma: no cover - opaque builtin
-        return None
-    for name in ("check_vma", "check_rep"):
-        if name in params:
-            return name
-    return None
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = True):
-    """The one shard_map entry point (DESIGN.md §14).
-
-    Wraps ``f`` as a per-shard program on ``mesh``, papering over the
-    ``check_rep`` -> ``check_vma`` kwarg rename between jax 0.4.x and
-    0.5.x.  ``check=False`` disables the replication checker — required
-    whenever an ``out_specs`` of ``P()`` is produced from device-varying
-    values (e.g. an all_gather'ed result that jax cannot prove replicated).
-    """
-    sm = _resolve_shard_map()
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    name = _check_kwarg(sm)
-    if name is not None:
-        return sm(f, **kw, **{name: check})
-    for name in ("check_vma", "check_rep"):  # opaque signature: probe
-        try:
-            return sm(f, **kw, **{name: check})
-        except TypeError:  # pragma: no cover - depends on installed jax
-            continue
-    return sm(f, **kw)  # pragma: no cover - kwarg dropped upstream
+from jax.sharding import AxisType
 
 
 def host_mesh(n_shards: int):
@@ -81,8 +27,6 @@ def host_mesh(n_shards: int):
 
 
 def _make_mesh(shape: tuple, axes: tuple):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 

@@ -825,3 +825,41 @@ def test_audit_detects_image_geometry_corruption(base_csr):
     img.degs[0] += 1  # degree drift: live-count / payload checks trip
     with pytest.raises(faultinject.AuditError):
         img.audit()
+
+
+def _pallas_walk(base_csr):
+    from repro.kernels.slot_walk import ops as sw_ops
+
+    img = REPRESENTATIONS["digraph"].from_csr(base_csr).to_walk_image()
+    return np.asarray(
+        sw_ops.slot_walk_image(img, 2, backend="pallas", interpret=True)
+    )
+
+
+def test_injected_pallas_fault_counts_fallthrough_and_logs_once(
+    base_csr, caplog, monkeypatch
+):
+    """A tripped pallas walk falls through visibly: the per-site counter
+    counts every fall-through and the first trip logs its cause once."""
+    clean = _pallas_walk(base_csr)
+    caplog.set_level("WARNING", logger=fallback.__name__)
+    monkeypatch.setattr(fallback.BREAKER, "clock", lambda: 0.0)  # stays open
+    faultinject.arm("slot_walk.pallas", times=2)
+    out = _pallas_walk(base_csr)  # both pallas tries die -> trip -> xla
+    faultinject.disarm()
+    assert fallback.LAST_USED["slot_walk"] == "xla"
+    assert fallback.BREAKER.fallthroughs["slot_walk"] == 1
+    _pallas_walk(base_csr)  # breaker open: skips pallas, no second log
+    assert fallback.BREAKER.fallthroughs["slot_walk"] == 2
+    logged = [r for r in caplog.records if "slot_walk" in r.getMessage()]
+    assert len(logged) == 1 and "pallas" in logged[0].getMessage()
+    assert logged[0].exc_info is not None  # the cause rides the record
+    np.testing.assert_allclose(out, clean, rtol=1e-5)
+
+
+def test_clean_pallas_run_leaves_fallthrough_counter_zero(base_csr, caplog):
+    caplog.set_level("WARNING", logger=fallback.__name__)
+    _pallas_walk(base_csr)
+    assert fallback.LAST_USED["slot_walk"] == "pallas"
+    assert sum(fallback.BREAKER.fallthroughs.values()) == 0
+    assert not caplog.records

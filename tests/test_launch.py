@@ -81,8 +81,9 @@ def test_divisibility_guard_drops_axes():
     assert "guard-ok" in r.stdout, r.stderr[-2000:]
 
 
-def test_shard_map_compat_version_shim():
-    """shard_map_compat must resolve the check kwarg on THIS jax and run."""
+def test_shard_map_check_vma_on_host_mesh():
+    """jax.shard_map runs on a host mesh with the replication check on
+    and off (the sharded walk's all_gather frontier needs it off)."""
     import subprocess
     import sys
     import textwrap
@@ -97,25 +98,19 @@ def test_shard_map_compat_version_shim():
         from jax.sharding import PartitionSpec as P
         from repro.launch import mesh as mesh_mod
 
-        sm = mesh_mod._resolve_shard_map()
-        assert callable(sm)
-        # kwarg detection: inspectable signatures must name one spelling
-        kw = mesh_mod._check_kwarg(sm)
-        assert kw in ("check_vma", "check_rep", None), kw
-
         mesh = mesh_mod.host_mesh(4)
-        f = mesh_mod.shard_map_compat(
+        f = jax.shard_map(
             lambda x: jax.lax.psum(x, "data"),
-            mesh=mesh, in_specs=P("data"), out_specs=P(), check=True)
+            mesh=mesh, in_specs=P("data"), out_specs=P(), check_vma=True)
         out = jax.jit(f)(jnp.arange(8, dtype=jnp.float32))
         assert float(out.sum()) == 28.0, out
-        # check=False path compiles too (device-varying out under P())
-        g = mesh_mod.shard_map_compat(
+        # check_vma=False compiles too (device-varying out under P())
+        g = jax.shard_map(
             lambda x: jax.lax.all_gather(x, "data", tiled=True),
-            mesh=mesh, in_specs=P("data"), out_specs=P(), check=False)
+            mesh=mesh, in_specs=P("data"), out_specs=P(), check_vma=False)
         out2 = jax.jit(g)(jnp.arange(8, dtype=jnp.float32))
         assert out2.shape == (8,) and float(out2[5]) == 5.0
-        print("shim-ok")
+        print("shard-map-ok")
         """
     )
     r = subprocess.run(
@@ -125,7 +120,7 @@ def test_shard_map_compat_version_shim():
         env={k: v for k, v in os.environ.items() if k != "XLA_FLAGS"},
         timeout=300,
     )
-    assert "shim-ok" in r.stdout, r.stderr[-2000:]
+    assert "shard-map-ok" in r.stdout, r.stderr[-2000:]
 
 
 def test_host_mesh_rejects_oversubscription():

@@ -75,6 +75,72 @@ def serve_and_verify(rep_kind, base, *, requests=24, update_every=4,
     return stats, torn, checked
 
 
+def _set_oracle_walk(edges: set, nv: int, row, steps: int, drop=None):
+    """The set-of-tuples + ``np.add.at`` walk the array oracle replaced."""
+    arr = np.array(sorted(edges), np.int64).reshape(-1, 2)
+    s, d = arr[:, 0], arr[:, 1]
+    v = np.asarray(row, np.float64)
+    for _ in range(steps):
+        nxt = np.zeros(nv, np.float64)
+        np.add.at(nxt, s, v[d])
+        if drop is not None:
+            nxt[drop] = 0.0
+        v = nxt
+    return v
+
+
+def test_array_oracle_matches_set_oracle_under_churn(base_csr):
+    """The sorted-key GenerationOracle walks every generation of a churned
+    stream exactly like the edge-set replica it replaced (re-inserts,
+    deletes of present and absent edges, upserts, degraded rows)."""
+    off = np.asarray(base_csr.offsets, np.int64)
+    rows = np.repeat(np.arange(N_V), np.diff(off))
+    edges = set(zip(rows.tolist(), np.asarray(base_csr.dst).tolist()))
+    oracle = launch_serve.GenerationOracle(base_csr)
+    rng = np.random.default_rng(5)
+    for gen in range(1, 7):
+        plans = [make_plan(rng, n_ins=20, n_del=15) for _ in range(2)]
+        if gen == 3:  # delete edges that exist, then put some back
+            live = sorted(edges)[:10]
+            plans.append(updates.plan_update(deletes=edgebatch.from_arrays(
+                [e[0] for e in live], [e[1] for e in live])))
+            plans.append(updates.plan_update(inserts=edgebatch.from_arrays(
+                [e[0] for e in live[:4]], [e[1] for e in live[:4]])))
+        for plan in plans:
+            oracle.record(gen, plan)
+            for u, v, rm in zip(plan.q_src.tolist(), plan.q_dst.tolist(),
+                                plan.q_del.tolist()):
+                (edges.discard if rm else edges.add)((u, v))
+        row = launch_serve.seed_visits_row(N_V, rng.integers(0, N_V, 3))
+        drop = np.arange(8, 16) if gen % 2 else None
+        np.testing.assert_array_equal(
+            oracle.walk(gen, row, 3, drop_rows=drop),
+            _set_oracle_walk(edges, N_V, row, 3, drop),
+        )
+
+
+def test_oracle_walk_many_matches_single_walks(base_csr):
+    """k rows walked together equal k single walks, on a graph with
+    empty rows and under a masked row set; an empty graph walks to 0."""
+    oracle = launch_serve.GenerationOracle(base_csr)
+    rng = np.random.default_rng(9)
+    rows = np.stack([
+        launch_serve.seed_visits_row(N_V, rng.integers(0, N_V, 4))
+        for _ in range(5)
+    ])
+    for drop in (None, np.arange(0, N_V, 3)):
+        many = oracle.walk_many(0, rows, 4, drop_rows=drop)
+        assert many.shape == rows.shape
+        for row, got in zip(rows, many):
+            np.testing.assert_array_equal(
+                got, oracle.walk(0, row, 4, drop_rows=drop)
+            )
+    empty = launch_serve.GenerationOracle(
+        csr_mod.from_coo(np.zeros(0, np.int64), np.zeros(0, np.int64), n=N_V)
+    )
+    assert not empty.walk_many(0, rows, 2).any()
+
+
 # ---------------------------------------------------------------------------
 # snapshot isolation: every served walk is consistent with its generation
 # ---------------------------------------------------------------------------

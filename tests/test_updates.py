@@ -163,6 +163,40 @@ def test_merge_rows_backend_parity(seed):
         np.testing.assert_array_equal(np.asarray(cnt), exp_c)
 
 
+@pytest.mark.parametrize("a,w,k", [(8, 256, 8), (5, 1024, 64), (3, 2048, 8)])
+def test_merge_rows_pallas_chunked_rows_exact_ids(a, w, k):
+    """Rows of several 128-lane chunks (band-limited placement across chunk
+    seams) merge exactly, ids above 2**24 included; rows wider than the
+    kernel's MAX_WIDTH take the XLA formulation."""
+    rng = np.random.default_rng(w + k)
+    d_rows = np.full((a, w), SENT, np.int32)
+    w_rows = np.zeros((a, w), np.float32)
+    degs = rng.integers(0, w - k + 1, a).astype(np.int32)
+    degs[0] = w - k  # a full row: survivors shift across every seam
+    b_d = np.full((a, k), SENT, np.int32)
+    b_w = np.zeros((a, k), np.float32)
+    b_l = np.zeros((a, k), np.int32)
+    for i in range(a):
+        vals = np.unique(rng.integers(0, 1 << 30, 2 * int(degs[i]) + 2))
+        vals = np.sort(rng.permutation(vals)[: degs[i]]).astype(np.int32)
+        degs[i] = vals.shape[0]
+        d_rows[i, : degs[i]] = vals
+        w_rows[i, : degs[i]] = rng.random(degs[i])
+        pool = np.concatenate([vals, rng.integers(0, 1 << 30, k)])
+        ops = np.unique(rng.choice(pool, k))[:k]
+        b_d[i, : ops.shape[0]] = ops
+        b_w[i, : ops.shape[0]] = rng.random(ops.shape[0])
+        b_l[i, : ops.shape[0]] = rng.integers(0, 2, ops.shape[0])
+    case = (d_rows, w_rows, degs, b_d, b_w, b_l)
+    exp_d, exp_w, exp_c = merge_rows_reference(*case)
+    od, ow, cnt = su_ops.merge_rows(
+        *(jnp.asarray(x) for x in case), backend="pallas", interpret=True
+    )
+    np.testing.assert_array_equal(np.asarray(od), exp_d)
+    np.testing.assert_allclose(np.asarray(ow), exp_w, rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(cnt), exp_c)
+
+
 # ---------------------------------------------------------------------------
 # mixed-batch apply on every representation
 # ---------------------------------------------------------------------------
