@@ -329,97 +329,102 @@ class DiGraph:
     def _apply_impl(self, plan: updates.UpdatePlan, donate: bool) -> int:
         if plan.n_ops == 0:
             return 0
-        if plan.n_ins:
-            s, d, _ = plan.insert_arrays()
-            self.add_vertices(np.concatenate([s, d]))
+        # the host plan: vertices, dirty rows, CP2AA allocation and the
+        # fused program's operands
+        with jax.profiler.TraceAnnotation("digraph.plan"):
+            if plan.n_ins:
+                s, d, _ = plan.insert_arrays()
+                self.add_vertices(np.concatenate([s, d]))
 
-        # shared dirty-row export: drops out-of-range rows and inert runs
-        sel, rows, deg_old, ins_count = plan.active_rows(
-            self.degrees, self.cap_v
-        )
-        if sel.shape[0] == 0:
-            return 0
-        old_caps = self.capacities[rows]
-        old_starts = self.starts[rows]
+            # shared dirty-row export: drops out-of-range rows and inert runs
+            sel, rows, deg_old, ins_count = plan.active_rows(
+                self.degrees, self.cap_v
+            )
+            if sel.shape[0] == 0:
+                return 0
+            old_caps = self.capacities[rows]
+            old_starts = self.starts[rows]
 
-        # CP2AA grow decisions (host): rows whose insert upper bound spills
-        # their class get a fresh block — the slot_update dispatch moves
-        # them as part of the same program.
-        ub = deg_old + ins_count
-        grow = ub > old_caps
-        new_caps = old_caps.copy()
-        new_starts = old_starts.copy()
-        if grow.any():
-            g_idx = np.nonzero(grow)[0]
-            need = alloc.edge_capacities(ub[grow])
-            new_caps[g_idx] = need
-            pending: list[int] = []
-            for i, c in zip(g_idx, need):
-                got = self.layout.try_alloc(int(c))
-                if got is None:
-                    pending.append(int(i))
-                else:
-                    new_starts[i] = got
-            if pending:
-                target = self.layout.grow_target(int(need.sum()))
-                self.dst, self.wgt, self.slot_rows = _jit_grow_buffer(
-                    target, self.cap_v
-                )(self.dst, self.wgt, self.slot_rows)
-                self._sealed.clear()  # grow copies into fresh buffers
-                self.layout.capacity = target
+            # CP2AA grow decisions (host): rows whose insert upper bound spills
+            # their class get a fresh block — the slot_update dispatch moves
+            # them as part of the same program.
+            ub = deg_old + ins_count
+            grow = ub > old_caps
+            new_caps = old_caps.copy()
+            new_starts = old_starts.copy()
+            if grow.any():
+                g_idx = np.nonzero(grow)[0]
+                need = alloc.edge_capacities(ub[grow])
+                new_caps[g_idx] = need
+                pending: list[int] = []
+                for i, c in zip(g_idx, need):
+                    got = self.layout.try_alloc(int(c))
+                    if got is None:
+                        pending.append(int(i))
+                    else:
+                        new_starts[i] = got
+                if pending:
+                    target = self.layout.grow_target(int(need.sum()))
+                    self.dst, self.wgt, self.slot_rows = _jit_grow_buffer(
+                        target, self.cap_v
+                    )(self.dst, self.wgt, self.slot_rows)
+                    self._sealed.clear()  # grow copies into fresh buffers
+                    self.layout.capacity = target
+                    self.stats.record_relayout()
+                    for i in pending:
+                        got = self.layout.try_alloc(int(new_caps[i]))
+                        assert got is not None
+                        new_starts[i] = got
                 self.stats.record_relayout()
-                for i in pending:
-                    got = self.layout.try_alloc(int(new_caps[i]))
-                    assert got is not None
-                    new_starts[i] = got
-            self.stats.record_relayout()
-        else:
-            self.stats.record_inplace()
+            else:
+                self.stats.record_inplace()
 
-        # ONE fused dispatch applies every pow-2 width group of the plan
-        # (DESIGN.md §12): gather + merge per group (exact capacity
-        # classes off-TPU, 128-slot tiles on TPU), then one write-back —
-        # the jit launch and the host counts sync are paid once per
-        # BATCH instead of once per width class.  Write-back picks the
-        # cheaper of two formulations (``choose_scatter``): TPU always
-        # scatters; off-TPU the full-buffer gather rebuild pays a
-        # ~cap_e-proportional constant (~5ns/slot/array + the host slot
-        # map) while scatters pay ~100ns per touched slot, so only a big
-        # arena with a proportionally tiny batch takes the scatter path
-        # (keeping small updates O(batch), not O(|E|)).
-        merge_backend = "pallas" if jax.default_backend() == "tpu" else "xla"
-        touched = int(new_caps.sum() + old_caps[grow].sum())
-        use_scatter = _su_ops.choose_scatter(self.cap_e, touched)
-        has_moves = bool(grow.any())
-        # per-buffer COW: dst/wgt are always written; the owner map only
-        # when a block moves — a sealed slot_rows stays snapshot-shared
-        # through every non-moving update.
-        self._detach("dst", "wgt", *(("slot_rows",) if has_moves else ()))
-        groups, layout = plan.fused_groups(
-            sel, rows, deg_old, grow,
-            old_starts, old_caps, new_starts, new_caps,
-            _su_ops.width_floor(), self.cap_v,
-        )
-        slot_map = owner_patch = None
-        rebuild_hi = 0
-        if not use_scatter:
-            rebuild_hi = _su_ops.quantized_prefix(
-                self.cap_e, int(self.layout.bump)
+            # ONE fused dispatch applies every pow-2 width group of the plan
+            # (DESIGN.md §12): gather + merge per group (exact capacity
+            # classes off-TPU, 128-slot tiles on TPU), then one write-back —
+            # the jit launch and the host counts sync are paid once per
+            # BATCH instead of once per width class.  Write-back picks the
+            # cheaper of two formulations (``choose_scatter``): TPU always
+            # scatters; off-TPU the full-buffer gather rebuild pays a
+            # ~cap_e-proportional constant (~5ns/slot/array + the host slot
+            # map) while scatters pay ~100ns per touched slot, so only a big
+            # arena with a proportionally tiny batch takes the scatter path
+            # (keeping small updates O(batch), not O(|E|)).
+            merge_backend = (
+                "pallas" if jax.default_backend() == "tpu" else "xla"
             )
-            slot_map, owner_patch = _su_ops.host_patch_layout(
-                layout, rows, old_starts, old_caps, new_starts, new_caps,
-                grow, rebuild_hi, self.cap_v, has_moves,
+            touched = int(new_caps.sum() + old_caps[grow].sum())
+            use_scatter = _su_ops.choose_scatter(self.cap_e, touched)
+            has_moves = bool(grow.any())
+            # per-buffer COW: dst/wgt are always written; the owner map only
+            # when a block moves — a sealed slot_rows stays snapshot-shared
+            # through every non-moving update.
+            self._detach("dst", "wgt", *(("slot_rows",) if has_moves else ()))
+            groups, layout = plan.fused_groups(
+                sel, rows, deg_old, grow,
+                old_starts, old_caps, new_starts, new_caps,
+                _su_ops.width_floor(), self.cap_v,
             )
-        # interval-cache refresh rides the same dispatch: when the shared
-        # walk image has warm [lo, hi) blocks, the program updates them
-        # from the merge counts and hands them back — the next walk
-        # skips the host geometry rebuild entirely.
-        img = self._image
-        blk = (
-            img._blocks
-            if img is not None and img.starts is self.starts
-            else None
-        )
+            slot_map = owner_patch = None
+            rebuild_hi = 0
+            if not use_scatter:
+                rebuild_hi = _su_ops.quantized_prefix(
+                    self.cap_e, int(self.layout.bump)
+                )
+                slot_map, owner_patch = _su_ops.host_patch_layout(
+                    layout, rows, old_starts, old_caps, new_starts, new_caps,
+                    grow, rebuild_hi, self.cap_v, has_moves,
+                )
+            # interval-cache refresh rides the same dispatch: when the shared
+            # walk image has warm [lo, hi) blocks, the program updates them
+            # from the merge counts and hands them back — the next walk
+            # skips the host geometry rebuild entirely.
+            img = self._image
+            blk = (
+                img._blocks
+                if img is not None and img.starts is self.starts
+                else None
+            )
         self.dst, self.wgt, self.slot_rows, counts_list, extra = (
             _su_ops.fused_apply(
                 self.dst, self.wgt, self.slot_rows, groups,

@@ -202,6 +202,7 @@ def merge_rows_pallas(
             jax.ShapeDtypeStruct((a_pad, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="merge_rows",
     )(deg2, d3, w3, *ops[3:])
     return (
         out_d.reshape(a_pad, w)[:a], out_w.reshape(a_pad, w)[:a],

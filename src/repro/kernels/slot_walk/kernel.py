@@ -80,6 +80,7 @@ def tile_cumsum(vals: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((t_pad, eb), jnp.float32),
         interpret=interpret,
+        name="tile_cumsum",
     )(vals)
     return out[:t] if t_pad != t else out
 

@@ -245,6 +245,7 @@ def make_blocked_step(gidx_p, block_lo, block_hi, num_vertices: int, *,
     l_lo = jnp.maximum(r_lo - 1, 0)
     l_hi = jnp.maximum(r_hi - 1, 0)
 
+    @jax.named_scope("slot_walk.step")
     def step(visits):  # [B, num_vertices] -> [B, num_vertices]
         b = visits.shape[0]
         zrow = jnp.zeros((b, 1), jnp.float32)

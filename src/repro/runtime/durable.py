@@ -61,6 +61,7 @@ import struct
 import zlib
 from typing import Iterator, Optional
 
+import jax
 import numpy as np
 
 from ..checkpoint import manager as ckpt
@@ -279,8 +280,9 @@ class UpdateJournal:
     def append(self, plan: updates.UpdatePlan, nv_bound: int) -> int:
         """Write one record; durable (to the OS) before this returns."""
         seq = self.next_seq
-        self._segment_for(seq)
-        self._write_flush(encode_record(seq, nv_bound, plan))
+        with jax.profiler.TraceAnnotation("durable.wal_append", seq=seq):
+            self._segment_for(seq)
+            self._write_flush(encode_record(seq, nv_bound, plan))
         self.next_seq = seq + 1
         return seq
 
@@ -301,12 +303,13 @@ class UpdateJournal:
             return []
         seq0 = self.next_seq
         seqs = list(range(seq0, seq0 + len(plans)))
-        self._segment_for(seq0)
-        buf = b"".join(
-            encode_record(s, nv, p)
-            for s, nv, p in zip(seqs, nv_bounds, plans)
-        )
-        self._write_flush(buf)
+        with jax.profiler.TraceAnnotation("durable.wal_append", seq=seq0):
+            self._segment_for(seq0)
+            buf = b"".join(
+                encode_record(s, nv, p)
+                for s, nv, p in zip(seqs, nv_bounds, plans)
+            )
+            self._write_flush(buf)
         self.next_seq = seq0 + len(plans)
         return seqs
 

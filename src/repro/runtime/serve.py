@@ -52,6 +52,18 @@ contract stronger than the sum of its parts:
   (chaos injection, ``rebuild_shard`` reintegration) on the writer
   thread, serialized with applies, with an optional reseal after.
 
+* **Host spans** — both threads mark their host work with
+  ``jax.profiler.TraceAnnotation``, so a profiler trace puts it on the
+  device's timeline: the dispatcher's ``serve.dispatch.wait`` (no walk
+  queued), ``serve.walk.prepare`` (shedding, rows, the ``[B, V]``
+  stack, the program's enqueue), ``serve.walk.device`` (waiting for
+  it) and ``serve.walk.fetch`` (device to host, resolving the
+  tickets); the writer's ``serve.writer.wait`` and ``serve.seal``.
+  The apply path adds ``durable.wal_append``, ``digraph.plan`` and
+  ``slot_update.dispatch``.  With no profiler running a span costs one
+  inactive TraceMe.  Tickets carry ``submitted_at``, ``taken_at`` and
+  ``done_at`` (updates also ``applied_at`` and ``acked_at``).
+
 The server is representation-agnostic: anything exposing
 ``apply(plan)`` (returning ``(rep, dm)`` or mutating in place) plus
 either ``to_walk_image()`` or its own ``seal_generation`` (all five
@@ -104,15 +116,20 @@ class Generation:
     image: walk_image.WalkImage
     #: updates applied to the live rep when this generation sealed
     seq: int
-    sealed_at: float
 
 
 class _Ticket:
-    """Base request handle: threading.Event + terminal outcome."""
+    """Base request handle: threading.Event + terminal outcome.
+
+    ``submitted_at``, ``taken_at`` (last popped off its queue by a server
+    thread, so a retried walk counts its last wait) and ``done_at`` (its
+    outcome set) are ``time.monotonic()`` readings, meaningful only as
+    differences; ``taken_at`` stays None for a request never taken.
+    """
 
     __slots__ = (
         "status", "reason", "retry_after", "error", "generation",
-        "submitted_at", "_done",
+        "submitted_at", "taken_at", "done_at", "_done",
     )
 
     def __init__(self):
@@ -122,11 +139,14 @@ class _Ticket:
         self.error: Optional[BaseException] = None
         self.generation: Optional[int] = None
         self.submitted_at = time.monotonic()
+        self.taken_at: Optional[float] = None
+        self.done_at: Optional[float] = None
         self._done = threading.Event()
 
     # -- resolution (server side) ---------------------------------------
     def _resolve(self, status: str) -> None:
         self.status = status
+        self.done_at = time.monotonic()
         self._done.set()
 
     def _reject(self, reason: str, retry_after: Optional[float] = None):
@@ -165,8 +185,7 @@ class WalkTicket(_Ticket):
     """
 
     __slots__ = ("seeds", "weights", "visits_row", "steps", "deadline",
-                 "attempts", "visits", "latency_s", "coverage",
-                 "down_shards")
+                 "attempts", "visits", "coverage", "down_shards")
 
     def __init__(self, seeds, weights, visits_row, steps, deadline):
         super().__init__()
@@ -177,9 +196,15 @@ class WalkTicket(_Ticket):
         self.deadline = deadline
         self.attempts = 0
         self.visits: Optional[np.ndarray] = None
-        self.latency_s: Optional[float] = None
         self.coverage: Optional[float] = None
         self.down_shards: tuple = ()
+
+    @property
+    def latency_s(self) -> Optional[float]:
+        """Seconds from submission to the outcome; None while pending."""
+        if self.done_at is None:
+            return None
+        return self.done_at - self.submitted_at
 
     def result(self, timeout: Optional[float] = None) -> np.ndarray:
         if not self.wait(timeout):
@@ -189,14 +214,23 @@ class WalkTicket(_Ticket):
 
 
 class UpdateTicket(_Ticket):
-    """Handle for one update; acked only once a sealed generation holds it."""
+    """Handle for one update; acked only once a sealed generation holds it.
 
-    __slots__ = ("plan", "dm")
+    ``applied_at`` is when the writer finished applying it to the live
+    representation; ``acked_at`` when a sealed generation acked it.
+    """
+
+    __slots__ = ("plan", "dm", "applied_at")
 
     def __init__(self, plan):
         super().__init__()
         self.plan = plan
         self.dm: Optional[int] = None
+        self.applied_at: Optional[float] = None
+
+    @property
+    def acked_at(self) -> Optional[float]:
+        return self.done_at if self.status == SERVED else None
 
     def result(self, timeout: Optional[float] = None) -> int:
         """Blocks until the update is visible to readers; returns ΔM."""
@@ -377,7 +411,6 @@ class WalkServer:
             out["generation"] = (
                 self._generation.gen_id if self._generation else -1
             )
-            out["ema_service_ms"] = self._ema_service_s * 1e3
             gen = self._generation
             out["coverage"] = (
                 float(getattr(gen.image, "coverage", 1.0)) if gen else 1.0
@@ -620,35 +653,42 @@ class WalkServer:
         for ack, and the writer retries on its next tick.
         """
         gen_id = self._gen_counter + (0 if initial else 1)
-        try:
-            faultinject.fire("serve.seal")
-            img = walk_image.seal_generation(self._rep, gen_id)
-        except Exception:
-            self._stats["seal_failures"] += 1
-            return False
-        self._gen_counter = gen_id
-        self._generation = Generation(
-            gen_id=gen_id, image=img, seq=self._seq, sealed_at=time.monotonic()
-        )
-        self._stats["seals"] += 1
-        for t in self._seal_pending:
-            t.generation = gen_id
-            t._resolve(SERVED)
-        self._seal_pending.clear()
+        with jax.profiler.TraceAnnotation("serve.seal", gen=gen_id):
+            try:
+                faultinject.fire("serve.seal")
+                img = walk_image.seal_generation(self._rep, gen_id)
+            except Exception:
+                self._stats["seal_failures"] += 1
+                return False
+            self._gen_counter = gen_id
+            self._generation = Generation(
+                gen_id=gen_id, image=img, seq=self._seq
+            )
+            self._stats["seals"] += 1
+            for t in self._seal_pending:
+                t.generation = gen_id
+                t._resolve(SERVED)
+            self._seal_pending.clear()
         return True
+
+    def _writer_idle(self) -> bool:
+        """No update, seal, admin op or quarantine waits (lock held)."""
+        return (
+            not self._upd_q and not self._closed
+            and not self._seal_pending and not self._admin_q
+            and not self._pending_quarantine
+        )
 
     def _writer_loop(self) -> None:
         audit_round = 0
         while True:
             with self._lock:
-                while (
-                    not self._upd_q and not self._closed
-                    and not self._seal_pending and not self._admin_q
-                    and not self._pending_quarantine
-                ):
-                    self._upd_cv.wait(0.05)
-                    if self.audit_every:
-                        break  # idle tick: keep the audit sweep moving
+                if self._writer_idle():
+                    with jax.profiler.TraceAnnotation("serve.writer.wait"):
+                        while self._writer_idle():
+                            self._upd_cv.wait(0.05)
+                            if self.audit_every:
+                                break  # idle tick: keep the audit sweep moving
                 if (
                     self._closed and not self._upd_q
                     and not self._seal_pending and not self._admin_q
@@ -658,6 +698,9 @@ class WalkServer:
                     self._upd_q.popleft()
                     for _ in range(min(len(self._upd_q), self.seal_group_max))
                 ]
+            taken = time.monotonic()
+            for t in group:
+                t.taken_at = taken
             dirty = self._drain_control()
             for t in group:
                 try:
@@ -671,6 +714,7 @@ class WalkServer:
                     else:
                         self._rep, dm = out
                     t.dm = int(dm)
+                    t.applied_at = time.monotonic()
                     self._seq += 1
                     with self._lock:
                         self._stats["updates_applied"] += 1
@@ -704,10 +748,12 @@ class WalkServer:
     def _take_batch(self) -> Optional[list]:
         """Pop up to batch_max same-steps requests (head-of-line steps)."""
         with self._lock:
-            while not self._walk_q:
-                if self._closed:
-                    return None
-                self._walk_cv.wait(0.05)
+            if not self._walk_q and not self._closed:
+                with jax.profiler.TraceAnnotation("serve.dispatch.wait"):
+                    while not self._walk_q and not self._closed:
+                        self._walk_cv.wait(0.05)
+            if not self._walk_q:
+                return None
             head = self._walk_q.popleft()
             batch = [head]
             kept = collections.deque()
@@ -719,6 +765,9 @@ class WalkServer:
                     kept.append(t)
             kept.extend(self._walk_q)
             self._walk_q = kept
+        taken = time.monotonic()
+        for t in batch:
+            t.taken_at = taken
         return batch
 
     def _visits_row(self, t: WalkTicket, nv: int) -> Optional[np.ndarray]:
@@ -744,48 +793,66 @@ class WalkServer:
         np.add.at(row, seeds, w)
         return row
 
+    def _batch_rows(self, batch: list):
+        """Shed the expired, build the survivors' rows against the serving
+        generation: ``(gen, tickets, v0)`` with ``v0`` the ``[B_pad, V]``
+        stack, B_pad a power of two >= 4 (the warm jit shapes)."""
+        now = time.monotonic()
+        live: list[WalkTicket] = []
+        for t in batch:
+            if t.deadline is not None and t.deadline <= now:
+                with self._lock:
+                    self._resolve_reject(t, "expired", walk=True)
+            else:
+                live.append(t)
+        gen = self._generation
+        nv = int(gen.image.nv)
+        rows, tickets = [], []
+        for t in live:
+            row = self._visits_row(t, nv)
+            if row is not None:
+                rows.append(row)
+                tickets.append(t)
+        if not tickets:
+            return gen, tickets, None
+        b_pad = max(alloc.next_pow2(len(tickets)), 4)
+        v0 = np.zeros((b_pad, nv), np.float32)
+        v0[: len(tickets)] = np.stack(rows)
+        return gen, tickets, v0
+
     def _dispatch_loop(self) -> None:
         primary = self.walk_backend
         if primary == "auto":
             primary = "pallas" if jax.default_backend() == "tpu" else "xla"
+        batch_id = 0
         while True:
             batch = self._take_batch()
             if batch is None:
                 return
-            now = time.monotonic()
-            live: list[WalkTicket] = []
-            for t in batch:
-                if t.deadline is not None and t.deadline <= now:
-                    with self._lock:
-                        self._resolve_reject(t, "expired", walk=True)
-                else:
-                    live.append(t)
-            if not live:
-                continue
-            gen = self._generation
-            nv = int(gen.image.nv)
-            rows, tickets = [], []
-            for t in live:
-                row = self._visits_row(t, nv)
-                if row is not None:
-                    rows.append(row)
-                    tickets.append(t)
-            if not tickets:
-                continue
-            b = len(tickets)
-            b_pad = max(alloc.next_pow2(b), 4)  # warm [B, V] jit shapes
-            v0 = np.zeros((b_pad, nv), np.float32)
-            v0[:b] = np.stack(rows)
-            t0 = time.monotonic()
+            batch_id += 1
             try:
-                faultinject.fire("serve.dispatch")
-                out = np.asarray(
-                    gen.image.walk(
+                with jax.profiler.TraceAnnotation(
+                    "serve.walk.prepare", batch=batch_id
+                ):
+                    gen, tickets, v0 = self._batch_rows(batch)
+                    if not tickets:
+                        continue
+                    t0 = time.monotonic()
+                    faultinject.fire("serve.dispatch")
+                    res = gen.image.walk(
                         int(tickets[0].steps),
                         backend=self.walk_backend,
                         visits0=v0,
                     )
-                )
+                meta = {"batch": batch_id, "gen": gen.gen_id,
+                        "walks": len(tickets)}
+                with jax.profiler.TraceAnnotation("serve.walk.device", **meta):
+                    jax.block_until_ready(res)
+                with jax.profiler.TraceAnnotation("serve.walk.fetch", **meta):
+                    out = np.asarray(res)
+                    del res  # free the device rows before the next batch
+                    self._fulfil(gen, tickets, out, time.monotonic() - t0,
+                                 primary)
             except Exception as e:
                 # a shard-attributed walk fault (§17): ask the writer to
                 # quarantine that shard, then retry the batch — against
@@ -794,29 +861,30 @@ class WalkServer:
                 sid = getattr(e, "sid", None)
                 if sid is not None:
                     self.request_quarantine(int(sid))
-                self._retry_or_fail(tickets, e)
-                continue
-            dt = time.monotonic() - t0
-            cov = float(getattr(gen.image, "coverage", 1.0))
-            downs = tuple(sorted(getattr(gen.image, "down", ()) or ()))
-            used = _fb.LAST_USED.get("slot_walk")
-            with self._lock:
-                if used is not None and used != primary:
-                    self._stats["breaker_fallbacks"] += 1
-                self._stats["batches"] += 1
-                self._stats["max_batch"] = max(self._stats["max_batch"], b)
-                self._stats["served"] += b
-                if cov < 1.0:
-                    self._stats["served_degraded"] += b
-                self._ema_service_s += 0.2 * (dt / b - self._ema_service_s)
-            done = time.monotonic()
-            for i, t in enumerate(tickets):
-                t.visits = out[i]
-                t.generation = gen.gen_id
-                t.coverage = cov
-                t.down_shards = downs
-                t.latency_s = done - t.submitted_at
-                t._resolve(SERVED)
+                self._retry_or_fail([t for t in batch if not t.done], e)
+
+    def _fulfil(self, gen: Generation, tickets: list, out: np.ndarray,
+                dt: float, primary: str) -> None:
+        """Account a served batch and resolve its tickets."""
+        b = len(tickets)
+        cov = float(getattr(gen.image, "coverage", 1.0))
+        downs = tuple(sorted(getattr(gen.image, "down", ()) or ()))
+        used = _fb.LAST_USED.get("slot_walk")
+        with self._lock:
+            if used is not None and used != primary:
+                self._stats["breaker_fallbacks"] += 1
+            self._stats["batches"] += 1
+            self._stats["max_batch"] = max(self._stats["max_batch"], b)
+            self._stats["served"] += b
+            if cov < 1.0:
+                self._stats["served_degraded"] += b
+            self._ema_service_s += 0.2 * (dt / b - self._ema_service_s)
+        for i, t in enumerate(tickets):
+            t.visits = out[i]
+            t.generation = gen.gen_id
+            t.coverage = cov
+            t.down_shards = downs
+            t._resolve(SERVED)
 
     def _retry_sleep_s(self, attempt: int) -> float:
         """Jittered exponential backoff: base·2^(attempt-1), capped, with

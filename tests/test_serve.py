@@ -613,3 +613,130 @@ def test_faultinject_armed_introspection():
     assert faultinject.armed() == ("serve.seal",)
     faultinject.disarm()
     assert faultinject.armed() == ()
+
+
+# ---------------------------------------------------------------------------
+# host spans on the profiler's timeline, and the request timestamps
+# ---------------------------------------------------------------------------
+
+#: every program span of the served path with a WAL under a DiGraph
+SERVED_SPANS = (
+    "serve.dispatch.wait", "serve.walk.prepare", "serve.walk.device",
+    "serve.walk.fetch", "serve.writer.wait", "durable.wal_append",
+    "digraph.plan", "slot_update.dispatch", "serve.seal",
+)
+
+
+def _durable_round(base, state_dir):
+    """Three update batches, each acked, then six walks, on a DiGraph
+    behind a WAL; returns (walk tickets, update tickets)."""
+    from repro.runtime import durable
+
+    dg = durable.DurableGraph(
+        REPRESENTATIONS["digraph"].from_csr(base),
+        str(state_dir / "wal"), str(state_dir / "ckpt"), fsync=True,
+    )
+    srv = serve_mod.WalkServer(dg, batch_max=4).start()
+    rng = np.random.default_rng(31)
+    upds = []
+    for _ in range(3):
+        upds.append(srv.submit_update(make_plan(rng)))
+        assert upds[-1].wait(60.0) and upds[-1].status == serve_mod.SERVED
+    walks = [srv.submit_walk(rng.integers(0, N_V, 3), steps=3)
+             for _ in range(6)]
+    for t in walks:
+        assert t.wait(60.0) and t.status == serve_mod.SERVED
+    srv.stop()
+    srv.assert_no_lost()
+    dg.close()
+    return walks, upds
+
+
+def _host_event_names(trace_dir) -> set:
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                names.update(ev.name.split("#")[0] for ev in line.events)
+    return names
+
+
+def test_served_path_spans_reach_the_host_plane(base_csr, tmp_path):
+    """A tiny served run under the profiler: every span of the served
+    path is on the host plane, and the answers are bit-identical to the
+    same run without the profiler (which also warms its programs)."""
+    import jax
+
+    plain, _ = _durable_round(base_csr, tmp_path / "plain")
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        traced, upds = _durable_round(base_csr, tmp_path / "traced")
+    names = _host_event_names(tmp_path / "trace")
+    assert set(SERVED_SPANS) <= names, set(SERVED_SPANS) - names
+    for a, b in zip(plain, traced):
+        np.testing.assert_array_equal(a.visits, b.visits)
+        assert a.generation == b.generation
+    for t in traced:
+        assert t.submitted_at <= t.taken_at <= t.done_at
+        assert t.latency_s == t.done_at - t.submitted_at
+    for t in upds:
+        assert t.submitted_at <= t.taken_at <= t.applied_at <= t.acked_at
+
+
+def test_ticket_timestamps_of_refusals_and_retries(base_csr):
+    rep = REPRESENTATIONS["digraph"].from_csr(base_csr)
+    # a retry sleeps 0.1-0.3 s before its request is taken again
+    srv = serve_mod.WalkServer(rep, retry_backoff=0.2).start()
+    bad = srv.submit_walk([N_V + 5], steps=2)
+    assert bad.wait(30.0) and bad.status == serve_mod.REJECTED
+    assert bad.submitted_at <= bad.taken_at <= bad.done_at
+    faultinject.arm("serve.dispatch", times=1)
+    t = srv.submit_walk([1, 2], steps=2)
+    assert t.wait(60.0) and t.status == serve_mod.SERVED
+    faultinject.disarm()
+    assert t.attempts == 1 and t.taken_at <= t.done_at
+    assert t.taken_at - t.submitted_at >= 0.1  # the retry's pop counts
+    srv.stop()
+    pending = serve_mod.WalkTicket([1], None, None, 2, None)
+    assert pending.latency_s is None and pending.taken_at is None
+    assert serve_mod.UpdateTicket(None).acked_at is None
+    assert "ema_service_ms" not in srv.stats()
+
+
+def test_dispatcher_holds_no_device_rows_between_batches(base_csr,
+                                                       monkeypatch):
+    """A served batch's ``[B, V]`` result is dropped once fetched, so the
+    next batch does not run beside it on the device.  (On the CPU a
+    fetched jax array stays alive through its numpy view, so the walk's
+    result is wrapped in an object that copies when fetched.)"""
+    import weakref
+
+    class Rows:
+        def __init__(self, a):
+            self.a = np.asarray(a)
+
+        def __array__(self, dtype=None, copy=None):
+            return self.a.copy()
+
+    made = []
+    walk = walk_image.WalkImage.walk
+
+    def tracked(self, *a, **kw):
+        rows = Rows(walk(self, *a, **kw))
+        made.append(weakref.ref(rows))
+        return rows
+
+    monkeypatch.setattr(walk_image.WalkImage, "walk", tracked)
+    srv = serve_mod.WalkServer(
+        REPRESENTATIONS["digraph"].from_csr(base_csr)).start()
+    t = srv.submit_walk([1, 2], steps=2)
+    assert t.wait(60.0) and t.status == serve_mod.SERVED
+    time.sleep(0.2)  # the dispatcher is back waiting for a request
+    assert made and all(r() is None for r in made)
+    srv.stop()
