@@ -282,8 +282,9 @@ def one_chip(args, limit: int, phases: Phases) -> list:
 
         def lower(b):
             return sw_ops.slot_walk_multi_blocked.lower(
-                sds((cap_e,), jnp.int32), sds((nv,), jnp.int32),
-                sds((nv,), jnp.int32), sds((b, nv), jnp.float32),
+                sds((cap_e,), jnp.int32), sds((cap_e,), jnp.int32),
+                sds((nv,), jnp.int32), sds((nv,), jnp.int32),
+                sds((b, nv), jnp.float32),
                 STEPS, nv, edges_hi=e_hi, engine="pallas",
             )
 

@@ -18,7 +18,10 @@ prefix counts are iota-compare sums or triangular matmuls — the TPU
 lowering has no ``cumsum``.  Matmuls that carry data values run at
 ``HIGHEST`` precision so the MXU keeps f32 accuracy.
 
-Inputs (ops.py pads the live prefix to whole tiles):
+``frontier_positions`` is the membership compare of the sparse frontier
+step (ops.py): each slot's position among a few hundred frontier ids.
+
+Inputs of the reduction (ops.py pads the live prefix to whole tiles):
   rows [T, EB]  int32 slot owners; dead/pad slots carry ``sink``
   vals [T, EB]  f32 gathered visits, zero on dead/pad slots
 Outputs:
@@ -32,6 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import row_tiling
 
@@ -40,6 +44,10 @@ HIGHEST = jax.lax.Precision.HIGHEST
 CUMSUM_ROWS = 1024
 #: tiles per grid step of the one-hot-rank reduction (looped row by row)
 PARTIAL_ROWS = 64
+#: tiles per grid step of the frontier membership compare
+POSITION_ROWS = 512
+#: tiles held in vector registers while every frontier id is compared
+POSITION_CHUNK = 32
 
 
 def _cumsum_kernel(vals_ref, out_ref):
@@ -83,6 +91,66 @@ def tile_cumsum(vals: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
         name="tile_cumsum",
     )(vals)
     return out[:t] if t_pad != t else out
+
+
+def _positions_kernel(fid_ref, gidx_ref, pos_ref):
+    n_rows = gidx_ref.shape[0]
+    width = fid_ref.shape[0]
+
+    def chunk(c, carry):
+        rows = pl.ds(pl.multiple_of(c * POSITION_CHUNK, POSITION_CHUNK),
+                     POSITION_CHUNK)
+        g = gidx_ref[rows, :]                    # a few vregs, kept live
+
+        def eight(i, p):                         # unrolled by hand: 8 ids
+            for k in range(8):
+                j = i * 8 + k
+                p = jnp.where(g == fid_ref[j], j, p)
+            return p
+
+        pos_ref[rows, :] = jax.lax.fori_loop(
+            0, width // 8, eight, jnp.full(g.shape, -1, jnp.int32)
+        )
+        return carry
+
+    jax.lax.fori_loop(0, n_rows // POSITION_CHUNK, chunk, 0)
+
+
+def frontier_positions(
+    gidx: jnp.ndarray, fid: jnp.ndarray, *, interpret: bool = False
+) -> jnp.ndarray:
+    """Frontier position of every slot: gidx [T, EB], fid [W] -> [T, EB].
+
+    ``pos[t, l]`` is the ``j`` with ``fid[j] == gidx[t, l]``, or -1.  The
+    frontier ids sit in SMEM and each block of slots stays in vector
+    registers while all ``W`` ids are compared against it, so a step
+    streams the gather plane once, with ``T * EB * W`` compares and no
+    ``[T * EB, W]`` intermediate.  Ids must be distinct and ``W`` a
+    multiple of 8; other tile counts than multiples of 32 pay one
+    padding copy.  Plain function, so callers inline it into their
+    programs.
+    """
+    t, eb = gidx.shape
+    t_pad = -(-t // POSITION_CHUNK) * POSITION_CHUNK
+    r = POSITION_ROWS
+    while t_pad % r:
+        r -= POSITION_CHUNK
+    if t_pad != t:
+        gidx = jnp.pad(gidx, ((0, t_pad - t), (0, 0)), constant_values=-1)
+    spec = pl.BlockSpec((r, eb), lambda i, fid: (i, 0))
+    pos = pl.pallas_call(
+        _positions_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(t_pad // r,),
+            in_specs=[spec],
+            out_specs=spec,
+        ),
+        out_shape=jax.ShapeDtypeStruct((t_pad, eb), jnp.int32),
+        interpret=interpret,
+        name="frontier_positions",
+    )(fid.astype(jnp.int32), gidx)
+    return pos[:t] if t_pad != t else pos
 
 
 def _kernel(rows_ref, vals_ref, part_ref, rank_ref, *, sink: int):

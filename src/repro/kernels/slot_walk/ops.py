@@ -15,6 +15,13 @@ the gather plane plus O(V) interval reads — roughly half the bytes of
 the segment-sum formulation.  The legacy rows-carrying paths remain for
 interval-less callers (raw arenas, the seed baseline).
 
+A batched walk whose seeds reach few vertices takes the sparse frontier
+step instead (``make_frontier_step``): it reads the gather plane once per
+step to find the slots its frontier hits, then only those slots and
+their owners in ``slot_rows``.  The batched program picks the form from
+the frontier it observes and falls back to the dense scan, in the same
+program, when the frontier outgrows the static widths.
+
 Both only process ``edges_hi`` slots (the arena's bump prefix, rounded up
 to a power of two by the caller so the jit cache stays O(log CAP_E))
 instead of the full CAP_E buffer — on updated graphs that alone is the
@@ -24,6 +31,7 @@ SENTINEL lane the allocator ever reserved.
 from __future__ import annotations
 
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +43,9 @@ from . import ref as _ref
 
 SENTINEL = util.SENTINEL
 EB = 128  # slots per tile (MXU-native)
+
+#: per thread: the sparse step count of the last batched blocked walk
+_last = threading.local()
 
 
 def _tiles(e: int) -> int:
@@ -362,6 +373,150 @@ def slot_walk_multi_xla(
     return visits
 
 
+# ---------------------------------------------------------------------------
+# sparse frontier step: a seeded walk reads the slots it hits, not all E
+# ---------------------------------------------------------------------------
+#: frontier widths the membership compare is compiled at; a step takes the
+#: narrowest that holds its frontier.  Widths past the first that covers
+#: every vertex are dropped.
+FRONTIER_WIDTHS = (32, 128, 512)
+#: hit slots a sparse step carries per vertex of the widest frontier
+HITS_PER_VERTEX = 4
+
+
+def _sparse_caps(num_vertices: int, t: int):
+    """``(widths, hit_tiles, hit_slots)`` for a walk of ``num_vertices``
+    over ``t`` tiles.  Every hit tile holds a hit slot, so ``hit_tiles``
+    is the slot cap clipped to the arena's tile count."""
+    widths = []
+    for w in FRONTIER_WIDTHS:
+        widths.append(w)
+        if w >= num_vertices:
+            break
+    slots = HITS_PER_VERTEX * widths[-1]
+    return tuple(widths), min(slots, t), slots
+
+
+def _cumsum(x):
+    """Inclusive int32 cumsum of a 1-D array, blocked by EB: an
+    iota-compare sum within each block, the block totals recursively.
+    ``jnp.cumsum`` makes the TPU compiler's time grow with the axis
+    (seconds at 10^5 entries, in every step of the program)."""
+    n = x.shape[0]
+    m = -(-n // EB)
+    blk = jnp.pad(x.astype(jnp.int32), (0, m * EB - n)).reshape(m, EB)
+    tri = jnp.arange(EB)[:, None] <= jnp.arange(EB)[None, :]
+    blk = jnp.sum(jnp.where(tri, blk[:, :, None], 0), axis=1)
+    if m > 1:
+        blk = blk + (_cumsum(blk[:, -1]) - blk[:, -1])[:, None]
+    return blk.reshape(-1)[:n]
+
+
+def _compact(count, n: int):
+    """The first ``n`` positions where the running count ``count`` steps
+    up, and which of them exist: ``(pos [n], ok [n])``."""
+    k = jnp.arange(n, dtype=count.dtype)
+    pos = jnp.searchsorted(count, k, side="right")
+    return jnp.minimum(pos, count.shape[0] - 1).astype(jnp.int32), k < count[-1]
+
+
+def _frontier(visits0, width: int):
+    """Union support of ``visits0`` [B, V] as ids [width] (padded with
+    ``V + 1``), values [B, width] and its size (which may exceed
+    ``width``).  Two levels: per-128-vertex tile counts, then the
+    vertices of the non-zero tiles alone."""
+    nv = visits0.shape[1]
+    vt = -(-nv // EB)
+    nz = jnp.pad(jnp.any(visits0 != 0, axis=0), (0, vt * EB - nv))
+    nz = nz.reshape(vt, EB)
+    counts = jnp.sum(nz, axis=1, dtype=jnp.int32)
+    tile, tile_ok = _compact(_cumsum(counts > 0), min(width, vt))
+    member = (nz[tile] & tile_ok[:, None]).reshape(-1)
+    at, ok = _compact(_cumsum(member), width)
+    vid = jnp.minimum(tile[at // EB] * EB + at % EB, nv - 1)
+    fid = jnp.where(ok, vid, nv + 1)
+    fv = jnp.where(ok[None, :], visits0[:, vid], 0.0)
+    return fid, fv, jnp.sum(counts)
+
+
+def _positions(gidx_p, fid, nf, widths, *, engine: str, interpret: bool):
+    """Each slot's position in the frontier ``fid``, or -1: [T, EB].
+
+    Pallas compares the gather plane with the narrowest width that holds
+    the ``nf`` live ids; XLA looks each slot up in the sorted ids."""
+    if engine == "pallas":
+        def compare(w):
+            return lambda f: _kernel.frontier_positions(
+                gidx_p, f[:w], interpret=interpret
+            )
+
+        pick = jnp.sum(nf > jnp.asarray(widths[:-1], jnp.int32))
+        return jax.lax.switch(pick, [compare(w) for w in widths], fid)
+    order = jnp.argsort(fid).astype(jnp.int32)
+    ids = fid[order]
+    i = jnp.minimum(jnp.searchsorted(ids, gidx_p), fid.shape[0] - 1)
+    return jnp.where(ids[i] == gidx_p, order[i], -1)
+
+
+def make_frontier_step(gidx_p, slot_rows, block_lo, block_hi,
+                       num_vertices: int, *, normalize: bool = False,
+                       engine: str = "xla", interpret: bool = False):
+    """Build the sparse walk step over a frontier of at most the widest
+    width: ``(fid, fv, nf, ok) -> (fid, fv, nf, ok)``.
+
+    Reads the gather plane once (the membership compare), then only the
+    hit slots: per-tile hit counts compact the hit tiles, those compact
+    the hit slots, each slot's owner comes from ``slot_rows``, and the
+    values combine by owner.  An owner's slots are one contiguous block,
+    so its hits are adjacent in slot order and the combine is a run sum
+    (a one-hot matmul), with no sort and no scatter.  Dead and pad slots
+    gather ``num_vertices`` and never hit.  ``ok`` drops, and the state
+    stops being exact, when the hits exceed the slot cap, the next
+    frontier exceeds the widest width, or a hit slot's owner does not
+    hold it in its ``[lo, hi)`` block.
+    """
+    widths, hit_tiles, hit_slots = _sparse_caps(
+        num_vertices, gidx_p.shape[0]
+    )
+    width = widths[-1]
+    cap_e = slot_rows.shape[0]
+
+    @jax.named_scope("slot_walk.frontier_step")
+    def step(fid, fv, nf, ok):
+        pos = _positions(gidx_p, fid, nf, widths, engine=engine,
+                         interpret=interpret)
+        counts = jnp.sum(pos >= 0, axis=1, dtype=jnp.int32)
+        tile, tile_ok = _compact(_cumsum(counts > 0), hit_tiles)
+        pos_h = jnp.where(tile_ok[:, None], pos[tile], -1).reshape(-1)
+        at, hit = _compact(_cumsum(pos_h >= 0), hit_slots)
+        slot = tile[at // EB] * EB + at % EB
+        own = slot_rows[jnp.minimum(slot, cap_e - 1)]
+        oc = jnp.clip(own, 0, num_vertices - 1)
+        held = (
+            (own == oc) & (block_lo[oc] <= slot) & (slot < block_hi[oc])
+        )
+        prev = jnp.concatenate([jnp.full((1,), -1, own.dtype), own[:-1]])
+        start = hit & (own != prev)
+        run = _cumsum(start) - 1
+        onehot = hit[:, None] & (
+            run[:, None] == jnp.arange(width, dtype=jnp.int32)[None, :]
+        )
+        vals = jnp.where(hit[None, :], fv[:, jnp.maximum(pos_h[at], 0)], 0.0)
+        nxt = jnp.dot(vals, onehot.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+        nid = jnp.min(jnp.where(onehot, own[:, None], num_vertices + 1),
+                      axis=0)
+        if normalize:
+            nxt = nxt / jnp.maximum(jnp.max(nxt, axis=1, keepdims=True), 1.0)
+        n_next = jnp.sum(start, dtype=jnp.int32)
+        ok = ok & (jnp.sum(counts) <= hit_slots) & (n_next <= width) & ~jnp.any(
+            hit & ~held
+        )
+        return nid, nxt, n_next, ok
+
+    return step
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -370,6 +525,7 @@ def slot_walk_multi_xla(
 )
 def slot_walk_multi_blocked(
     dst: jnp.ndarray,
+    slot_rows: jnp.ndarray,
     block_lo: jnp.ndarray,
     block_hi: jnp.ndarray,
     visits0: jnp.ndarray,
@@ -380,13 +536,20 @@ def slot_walk_multi_blocked(
     normalize: bool = False,
     engine: str = "xla",
     interpret: bool = False,
-) -> jnp.ndarray:
-    """Batched scatter-free prefix-sum walk: visits0 [B, V] -> [B, V].
+):
+    """Batched walk: visits0 [B, V] -> ([B, V], steps run sparse).
 
-    The blocked step is natively batched — the interval index arithmetic
-    is shared, only the gathered values and prefix sums carry a batch
-    dim (the Pallas intra-tile cumsum sees B*T independent tiles of the
-    same kernel).
+    A batch whose union support fits the widest frontier width walks in
+    the sparse form (``make_frontier_step``), touching only the slots
+    its frontier hits; the answer is written into a zero [B, V].  A
+    batch that does not fit, or whose frontier outgrows the caps at any
+    step, runs the dense scatter-free prefix-sum scan
+    (``make_blocked_step``) from ``visits0`` instead, in the same
+    program.  The dense step is natively batched — the interval index
+    arithmetic is shared, only the gathered values and prefix sums carry
+    a batch dim (the Pallas intra-tile cumsum sees B*T independent tiles
+    of the same kernel).  The second result, an int32 scalar, is
+    ``steps`` when the sparse form answered and 0 when the dense did.
     """
     gidx_p = _prep_gidx(dst, num_vertices, edges_hi)
     step = make_blocked_step(
@@ -394,16 +557,38 @@ def slot_walk_multi_blocked(
         engine=engine, interpret=interpret,
     )
 
-    def body(visits, _):
-        nxt = step(visits)
-        if normalize:
-            nxt = nxt / jnp.maximum(
-                jnp.max(nxt, axis=1, keepdims=True), 1.0
-            )
-        return nxt, None
+    def dense():
+        def body(visits, _):
+            nxt = step(visits)
+            if normalize:
+                nxt = nxt / jnp.maximum(
+                    jnp.max(nxt, axis=1, keepdims=True), 1.0
+                )
+            return nxt, None
 
-    visits, _ = jax.lax.scan(body, visits0, None, length=steps)
-    return visits
+        visits, _ = jax.lax.scan(body, visits0, None, length=steps)
+        return visits
+
+    sparse_step = make_frontier_step(
+        gidx_p, slot_rows, block_lo, block_hi, num_vertices,
+        normalize=normalize, engine=engine, interpret=interpret,
+    )
+    width = _sparse_caps(num_vertices, gidx_p.shape[0])[0][-1]
+    fid, fv, nf = _frontier(visits0, width)
+
+    def body(state, _):
+        live = state[3] & (state[2] > 0)
+        return jax.lax.cond(live, sparse_step, lambda *s: s, *state), None
+
+    (fid, fv, _nf, ok), _ = jax.lax.scan(
+        body, (fid, fv, nf, nf <= width), None, length=steps,
+    )
+    visits = jax.lax.cond(
+        ok,
+        lambda: jnp.zeros_like(visits0).at[:, fid].set(fv, mode="drop"),
+        dense,
+    )
+    return visits, jnp.where(ok, steps, 0).astype(jnp.int32)
 
 
 @functools.partial(
@@ -512,11 +697,13 @@ def slot_walk(
             )
         if visits0 is not None:
             if block_lo is not None and block_hi is not None:
-                return slot_walk_multi_blocked(
-                    dst, block_lo, block_hi, visits0, steps,
+                out, sparse = slot_walk_multi_blocked(
+                    dst, slot_rows, block_lo, block_hi, visits0, steps,
                     num_vertices, edges_hi=edges_hi, normalize=normalize,
                     engine=b, interpret=interpret,
                 )
+                _last.sparse_steps = sparse
+                return out
             if b == "pallas":
                 return slot_walk_multi_pallas(
                     dst, slot_rows, visits0, steps, num_vertices,
@@ -543,8 +730,18 @@ def slot_walk(
             edges_hi=edges_hi, normalize=normalize,
         )
 
+    _last.sparse_steps = None
     out, _used = _fb.run_chain("slot_walk", backend, _dispatch)
     return out
+
+
+def take_sparse_steps():
+    """The steps that this thread's last ``slot_walk`` ran in the sparse
+    frontier form, as the device int32 scalar its program returned (None
+    where no batched blocked program answered), and forget it.  Fetch it
+    with the walk's result: it is ready when the result is."""
+    sparse, _last.sparse_steps = getattr(_last, "sparse_steps", None), None
+    return sparse
 
 
 def slot_walk_image(

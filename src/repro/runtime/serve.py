@@ -83,6 +83,7 @@ import numpy as np
 
 from ..core import alloc, updates, walk_image
 from ..kernels import fallback as _fb
+from ..kernels.slot_walk import ops as _sw_ops
 from . import faultinject
 
 #: Ticket terminal states.  "pending" is the only non-terminal one.
@@ -275,6 +276,8 @@ def _fresh_stats() -> dict:
         # engine health
         "seals": 0, "seal_failures": 0, "batches": 0, "max_batch": 0,
         "dispatch_retries": 0, "breaker_fallbacks": 0,
+        # walk steps answered by the sparse frontier form or the dense scan
+        "walk_steps_sparse": 0, "walk_steps_dense": 0,
         # shard failover (§17)
         "shard_quarantines": 0, "audit_detections": 0,
         "served_degraded": 0, "admin_ops": 0,
@@ -839,20 +842,20 @@ class WalkServer:
                         continue
                     t0 = time.monotonic()
                     faultinject.fire("serve.dispatch")
+                    steps = int(tickets[0].steps)
                     res = gen.image.walk(
-                        int(tickets[0].steps),
-                        backend=self.walk_backend,
-                        visits0=v0,
+                        steps, backend=self.walk_backend, visits0=v0
                     )
+                    sparse = _sw_ops.take_sparse_steps()
                 meta = {"batch": batch_id, "gen": gen.gen_id,
                         "walks": len(tickets)}
                 with jax.profiler.TraceAnnotation("serve.walk.device", **meta):
                     jax.block_until_ready(res)
                 with jax.profiler.TraceAnnotation("serve.walk.fetch", **meta):
-                    out = np.asarray(res)
+                    out, sparse = jax.device_get((res, sparse))
                     del res  # free the device rows before the next batch
                     self._fulfil(gen, tickets, out, time.monotonic() - t0,
-                                 primary)
+                                 primary, steps, sparse)
             except Exception as e:
                 # a shard-attributed walk fault (§17): ask the writer to
                 # quarantine that shard, then retry the batch — against
@@ -864,8 +867,12 @@ class WalkServer:
                 self._retry_or_fail([t for t in batch if not t.done], e)
 
     def _fulfil(self, gen: Generation, tickets: list, out: np.ndarray,
-                dt: float, primary: str) -> None:
-        """Account a served batch and resolve its tickets."""
+                dt: float, primary: str, steps: int,
+                sparse: Optional[int]) -> None:
+        """Account a served batch and resolve its tickets.  ``sparse`` is
+        the steps the walk program ran in the sparse frontier form (None
+        where no such program ran: every step was dense)."""
+        sparse = 0 if sparse is None else int(sparse)
         b = len(tickets)
         cov = float(getattr(gen.image, "coverage", 1.0))
         downs = tuple(sorted(getattr(gen.image, "down", ()) or ()))
@@ -876,6 +883,8 @@ class WalkServer:
             self._stats["batches"] += 1
             self._stats["max_batch"] = max(self._stats["max_batch"], b)
             self._stats["served"] += b
+            self._stats["walk_steps_sparse"] += sparse
+            self._stats["walk_steps_dense"] += steps - sparse
             if cov < 1.0:
                 self._stats["served_degraded"] += b
             self._ema_service_s += 0.2 * (dt / b - self._ema_service_s)

@@ -152,6 +152,9 @@ def test_served_walks_match_generation_oracle(rep_kind, base_csr):
     assert stats["served"] == 24
     assert checked == 24 and torn == 0
     assert stats["seals"] >= 2  # updates actually advanced generations
+    # seeded walks on a small graph: every step ran in the sparse form
+    assert stats["walk_steps_sparse"] == 3 * stats["batches"] > 0
+    assert stats["walk_steps_dense"] == 0
 
 
 @pytest.mark.parametrize("rep_kind", ["coo", "lazy", "vector2d"])
@@ -160,6 +163,7 @@ def test_served_walks_match_oracle_all_reps(rep_kind, base_csr):
         rep_kind, base_csr, requests=12, update_every=3
     )
     assert torn == 0 and checked == stats["served"] == 12
+    assert stats["walk_steps_sparse"] > 0 and stats["walk_steps_dense"] == 0
 
 
 def test_sealed_generation_immutable_under_writer(base_csr):

@@ -76,6 +76,14 @@ def test_tile_cumsum_compiles(sds):
     _compile(sw_kernel.tile_cumsum, sds((rows, 128), jnp.float32))
 
 
+@pytest.mark.parametrize("width", sw_ops.FRONTIER_WIDTHS)
+def test_frontier_positions_compiles(sds, width):
+    _compile(
+        sw_kernel.frontier_positions,
+        sds((EDGES_HI // 128, 128), jnp.int32), sds((width,), jnp.int32),
+    )
+
+
 def test_slot_walk_partials_compiles(sds):
     t = EDGES_HI // 128
     _compile(
@@ -104,14 +112,18 @@ def test_count_degrees_pallas_compiles(sds):
 
 def test_served_walk_step_fits_one_chip(sds):
     """The jitted batched walk the server dispatches, Pallas prefix
-    engine, at the one-chip served shape: compiles and fits 16 GB."""
+    engine, at the one-chip served shape, with the slot-owner plane of
+    its sparse frontier step: compiles, holds the frontier compare
+    kernel, and fits 16 GB."""
     compiled = _compile(
-        lambda d, lo, hi, v: sw_ops.slot_walk_multi_blocked(
-            d, lo, hi, v, 4, NV, edges_hi=EDGES_HI, engine="pallas"
+        lambda d, r, lo, hi, v: sw_ops.slot_walk_multi_blocked(
+            d, r, lo, hi, v, 4, NV, edges_hi=EDGES_HI, engine="pallas"
         ),
-        sds((CAP_E,), jnp.int32), sds((NV,), jnp.int32),
-        sds((NV,), jnp.int32), sds((BATCH, NV), jnp.float32),
+        sds((CAP_E,), jnp.int32), sds((CAP_E,), jnp.int32),
+        sds((NV,), jnp.int32), sds((NV,), jnp.int32),
+        sds((BATCH, NV), jnp.float32),
     )
+    assert "frontier_positions" in compiled.as_text()
     ma = compiled.memory_analysis()
     total = (
         ma.argument_size_in_bytes + ma.output_size_in_bytes
